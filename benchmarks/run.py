@@ -4,11 +4,14 @@
 
 ``--with-measured`` additionally executes the scaled-down distributed
 plans on an 8-host-device mesh (slower; spawns a subprocess so the main
-process keeps its single-device view).
+process keeps its single-device view).  That subprocess is a host-device
+simulation: it runs with ``JAX_PLATFORMS=cpu``, so it never competes
+with this process for an accelerator.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import subprocess
 import sys
 
@@ -18,6 +21,8 @@ def main(argv=None) -> int:
     ap.add_argument("--with-measured", action="store_true")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (analysis, ffnn, fusion, matmul, nn_search,
                             oocore, resilience, robustness, roofline,
                             serve, train)
@@ -46,7 +51,8 @@ def main(argv=None) -> int:
             print(f"SECTION FAILED: {e!r}")
 
     if args.with_measured:
-        print(f"\n{'=' * 72}\nmeasured 8-device runs (subprocess)\n"
+        print(f"\n{'=' * 72}\nmeasured 8-device runs (subprocess, "
+              f"simulated on 8 CPU host devices, JAX_PLATFORMS=cpu)\n"
               f"{'=' * 72}")
         code = (
             "import os;"
@@ -58,7 +64,8 @@ def main(argv=None) -> int:
             "mesh = make_mesh((8,), ('sites',));"
             "print('\\n'.join(str(r) for r in matmul.measured(mesh)))")
         proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, timeout=1200)
+                              capture_output=True, text=True, timeout=1200,
+                              env={**os.environ, "JAX_PLATFORMS": "cpu"})
         print(proc.stdout or proc.stderr)
         failures += proc.returncode != 0
     return 1 if failures else 0

@@ -52,7 +52,8 @@ import jax.numpy as jnp
 
 from repro.core import kernels_registry as kr
 from repro.core.compile import compile_tra
-from repro.core.interp import _evaluate_ia, _evaluate_tra, _jit_ia_plans
+from repro.core.interp import (_evaluate_ia, _evaluate_tra, _jit_ia_plans,
+                               _placer)
 from repro.core.optimize import OptimizeResult, optimize as _optimize
 from repro.core.plan import (IAInput, IANode, Placement, TraInput, TraNode,
                              TypeInfo, as_node, describe, infer, postorder)
@@ -70,6 +71,14 @@ _EXECUTOR_FALLBACKS = {
     "jit": ("reference",),
 }
 DEFAULT_OOM_LADDER_START = 64
+
+
+def _is_compile_class(err: BaseException) -> bool:
+    """Failures the executor fallback ladder may degrade past: injected
+    compile failures, an executor's unsupported subset, runtime errors."""
+    from repro.core.faults import CompileFailure
+    return isinstance(err, (CompileFailure, NotImplementedError,
+                            jax.errors.JaxRuntimeError))
 
 
 def _validate_chunk(chunk) -> None:
@@ -862,19 +871,15 @@ class Engine:
         unchanged.  Returns ``(compiled, executor, err)``; ``compiled`` is
         ``None`` when no rung succeeded (re-raise ``err``).
         """
-        from repro.core.faults import CompileFailure
-        def compile_class(e):
-            return isinstance(e, (CompileFailure, NotImplementedError)) \
-                or type(e).__name__ == "XlaRuntimeError"
         ladder = _EXECUTOR_FALLBACKS.get(executor, ())
-        if not self.degrade or not ladder or not compile_class(err):
+        if not self.degrade or not ladder or not _is_compile_class(err):
             return None, executor, err
         for fb in ladder:
             try:
                 compiled = self._compile(roots, placements, target, fb,
                                          multi, chunk, stream=stream)
             except Exception as err2:
-                if not compile_class(err2):
+                if not _is_compile_class(err2):
                     return None, executor, err2
                 err = err2
                 continue
@@ -1169,11 +1174,15 @@ class Engine:
                             multi, jitted=jfn, input_names=tuple(names))
 
     def _gspmd_call(self, plans, out_infos, chunk, ctx=None):
-        jfn, names = _jit_ia_plans(plans, self.mesh, chunk=chunk,
-                                   budget=self.memory_budget, ctx=ctx)
+        jfn, names, shardings = _jit_ia_plans(
+            plans, self.mesh, chunk=chunk, budget=self.memory_budget,
+            ctx=ctx)
+        # jit refuses a committed array laid out other than its input
+        # sharding, such as a train step's output fed back as an input
+        place = _placer(shardings)
 
         def call(env):
-            datas = jfn(*(env[n].data for n in names))
+            datas = jfn(*place(tuple(env[n].data for n in names)))
             return tuple(TensorRelation(d, oi.rtype, oi.mask)
                          for d, oi in zip(datas, out_infos))
 

@@ -47,6 +47,8 @@ import dataclasses
 import time
 from typing import List, Optional, Tuple, Union
 
+import jax
+
 
 class FaultError(RuntimeError):
     """Base class of all injected faults."""
@@ -81,15 +83,15 @@ def is_transient(err: BaseException) -> bool:
     """Classify an execution failure against the fault taxonomy.
 
     True for the injected transient kinds (:data:`TRANSIENT_FAULTS`),
-    for real XLA runtime failures (``XlaRuntimeError`` — device resets,
-    allocation failures), and for numeric-guard trips
+    for real runtime failures (:class:`jax.errors.JaxRuntimeError` —
+    device resets, allocation failures), and for numeric-guard trips
     (:class:`repro.core.guards.NumericsError`): recomputation is
     deterministic, so a *persistent* poisoning exhausts any retry budget
     while injected/transient corruption clears on the next attempt.
     """
     if isinstance(err, TRANSIENT_FAULTS):
         return True
-    if any(t.__name__ == "XlaRuntimeError" for t in type(err).__mro__):
+    if isinstance(err, jax.errors.JaxRuntimeError):
         return True
     try:
         from repro.core.guards import NumericsError

@@ -202,11 +202,11 @@ def check_output_rel(rel, label: str) -> None:
 
 
 def is_oom_error(exc: BaseException) -> bool:
-    """True for injected DeviceOOM and real XLA RESOURCE_EXHAUSTED."""
+    """True for injected DeviceOOM and real RESOURCE_EXHAUSTED failures."""
     from repro.core.faults import DeviceOOM
     if isinstance(exc, DeviceOOM):
         return True
     return ("RESOURCE_EXHAUSTED" in str(exc)
             or "Out of memory" in str(exc)
-            or type(exc).__name__ == "XlaRuntimeError"
+            or isinstance(exc, jax.errors.JaxRuntimeError)
             and "memory" in str(exc).lower())

@@ -20,20 +20,16 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 
 from repro.core import tra
-from repro.core.interp import _merge_ia_inputs, _pspec_for, _warn_deprecated
+from repro.core.interp import (_merge_ia_inputs, _placer, _pspec_for,
+                               _warn_deprecated)
 from repro.core.plan import (Bcast, FusedJoinAgg, IAConst, IAInput, IANode,
                              LocalAgg, LocalConcat, LocalFilter, LocalJoin,
                              LocalMap, LocalPad, LocalTile, Placement, Shuf,
                              TypeInfo, as_node, infer, postorder)
 from repro.core.tra import RelType, TensorRelation
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                      # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _local_rtype(info: TypeInfo, mesh: Mesh) -> RelType:
@@ -318,12 +314,14 @@ def _build_shardmap(roots, mesh: Mesh, chunk=None,
     # multi-root programs (the train-step loop runs one of these per
     # step).  Everything inside is static-shape jnp (masks are rejected
     # above), so jit is always legal here.
-    fn = jax.jit(_shard_map(local_fn, mesh=mesh, in_specs=in_specs,
-                            out_specs=tuple(out_specs)))
+    fn = jax.jit(jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                               out_specs=tuple(out_specs)))
+    # inputs arrive in their in_specs layout, so a train step's outputs
+    # fed back as inputs do not retrace and recompile ``fn``
+    place = _placer(tuple(NamedSharding(mesh, spec) for spec in in_specs))
 
     def call(env: Dict[str, TensorRelation]):
-        arrays = [env[n].data for n in names]
-        outs = fn(*arrays)
+        outs = fn(*place(tuple(env[n].data for n in names)))
         return tuple(TensorRelation(o, oi.rtype)
                      for o, oi in zip(outs, out_infos))
 

@@ -410,14 +410,14 @@ def _zero_fill(data: jax.Array, mask: Optional[np.ndarray],
 
 def _fused_matmul_2d(g: _JoinGeometry, left: TensorRelation,
                      right: TensorRelation, jkl: Tuple[int, ...],
-                     gb: Tuple[int, ...]) -> jax.Array:
+                     gb: Tuple[int, ...], impl: str = "auto") -> jax.Array:
     """Collapse Σ∘⋈_(matMul→matAdd) into ONE blocked 2-D matmul.
 
     Valid when every joined key dim is reduced and every reduced dim is
     joined: the whole expression is exactly ``(I·m, K·c) @ (K·c, J·n)`` —
     the paper's claim that the TRA plan *is* the hand-tuned contraction.
     Dispatches through :func:`repro.kernels.matmul.ops.matmul`, which
-    selects the Pallas MXU kernel on TPU (``impl="auto"``) and the XLA
+    with ``impl="auto"`` selects the Pallas MXU kernel on TPU and the XLA
     matmul elsewhere.
     """
     from repro.kernels.matmul.ops import matmul as matmul_op
@@ -440,7 +440,7 @@ def _fused_matmul_2d(g: _JoinGeometry, left: TensorRelation,
     R2 = jnp.transpose(g.rdata_t, rperm).reshape(
         math.prod(g.f_out_l[ax] for ax in jkl) * c,
         math.prod(g.out_key_shape[ax] for ax in kept_r) * n)
-    out2 = matmul_op(L2, R2, impl="auto")
+    out2 = matmul_op(L2, R2, impl=impl)
     # back to blocks: (kept_l..., m, kept_r..., n) → gb order ++ (m, n)
     out = out2.reshape(tuple(g.f_out_l[ax] for ax in kept_l) + (m,)
                        + tuple(g.out_key_shape[ax] for ax in kept_r) + (n,))
@@ -551,7 +551,7 @@ def fused_join_agg(left: TensorRelation, right: TensorRelation,
                    agg_kernel: Kernel, *,
                    chunk=None,
                    budget: Optional[int] = None,
-                   ctx=None, node=None) -> TensorRelation:
+                   ctx=None, node=None, spmd: bool = False) -> TensorRelation:
     """Σ_(groupBy, aggOp) ∘ ⋈_(jkl, jkr, projOp) without the grid.
 
     Semantically identical to ``agg(join(left, right, ...), group_by, ...)``
@@ -575,6 +575,10 @@ def fused_join_agg(left: TensorRelation, right: TensorRelation,
     ``ctx.stream`` is set (the engine's OOM degradation ladder), forces
     even contraction-shaped pairs onto the chunked streaming fallback so
     peak live memory is bounded by ``chunk`` slices.
+
+    ``spmd=True`` marks a trace that XLA's SPMD partitioner will split
+    across devices (the gspmd executor).  It cannot partition a Mosaic
+    kernel, so the 2-D matmul then lowers to XLA's own dot.
 
     Falls back to the unfused pair when nothing is actually reduced or when
     holes cannot be identity-filled — the unfused path remains the
@@ -610,7 +614,8 @@ def fused_join_agg(left: TensorRelation, right: TensorRelation,
                                bytes_live=in_bytes + out_bytes)
         if (join_kernel.name == "matMul" and g.lmask is None
                 and g.rmask_t is None and set(reduce_dims) == set(jkl)):
-            data = _fused_matmul_2d(g, left, right, jkl, gb)
+            data = _fused_matmul_2d(g, left, right, jkl, gb,
+                                    impl="jnp" if spmd else "auto")
         else:
             data = _fused_einsum(g, left, right, join_kernel, gb)
         return TensorRelation(

@@ -53,6 +53,9 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *, block_m: int = 512,
     if m % block_m or n % block_n or k % block_k:
         raise ValueError("shapes must divide block sizes (pad in ops.py)")
     out_dtype = out_dtype or a.dtype
+    # inside shard_map the output varies over the mesh axes its operands
+    # vary over (the empty set outside one)
+    vma = jax.typeof(a).vma | jax.typeof(b).vma
     grid = (m // block_m, n // block_n, k // block_k)
     return pl.pallas_call(
         functools.partial(_matmul_kernel, nk=grid[2]),
@@ -62,7 +65,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *, block_m: int = 512,
             pl.BlockSpec((block_k, block_n), lambda i, j, l: (l, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, l: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
         interpret=interpret,
     )(a, b)

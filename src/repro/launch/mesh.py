@@ -12,29 +12,14 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """`jax.make_mesh` with Auto axis types across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer jax;
-    older releases are implicitly Auto, so simply omit the argument there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """`jax.make_mesh` with every axis Auto (GSPMD-partitioned)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_abstract_mesh(shape, axes):
-    """`jax.sharding.AbstractMesh` across jax versions.
-
-    Newer jax takes ``(axis_shapes, axis_names)``; 0.4.x takes a single
-    tuple of ``(name, size)`` pairs.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """A device-free `jax.sharding.AbstractMesh` of the given shape."""
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -135,6 +135,8 @@ def main(argv=None) -> int:
                     help="dense-oracle mesh, e.g. 2x2")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.dense_oracle:
         return _dense_oracle(args)
 

@@ -17,15 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                      # jax < 0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# pvary marks an array as varying over manual axes (new shard_map type
-# system); older jax has no notion of it and needs no marker.
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 
 def gpipe(stage_fn: Callable, mesh: Mesh, stage_axis: str):
     """Build a pipelined ``(stacked_params, microbatches) -> outputs`` fn.
@@ -46,8 +37,8 @@ def gpipe(stage_fn: Callable, mesh: Mesh, stage_axis: str):
         buf = jnp.zeros_like(xs[0])                  # incoming activation
         outs = jnp.zeros_like(xs)
         # carries become stage-varying after the first ppermute
-        buf = _pvary(buf, (stage_axis,))
-        outs = _pvary(outs, (stage_axis,))
+        buf = jax.lax.pcast(buf, (stage_axis,), to="varying")
+        outs = jax.lax.pcast(outs, (stage_axis,), to="varying")
 
         def tick(t, carry):
             buf, outs = carry
@@ -72,7 +63,7 @@ def gpipe(stage_fn: Callable, mesh: Mesh, stage_axis: str):
 
     def run(stacked_params, microbatches):
         pspec = jax.tree.map(lambda _: P(stage_axis), stacked_params)
-        return _shard_map(
+        return jax.shard_map(
             local, mesh=mesh,
             in_specs=(pspec, P()), out_specs=P(),
         )(stacked_params, microbatches)

@@ -44,14 +44,11 @@ def device_memory_budget(device=None) -> Optional[int]:
     key = (device.platform, device.id)
     if key not in _calibrated:
         limit = None
-        try:
-            stats = device.memory_stats()
-            if stats:
-                limit = int(stats.get("bytes_limit")
-                            or stats.get("bytes_reservable_limit") or 0)
-                limit = limit or None
-        except Exception:       # backend without memory introspection
-            limit = None
+        stats = device.memory_stats()     # None on the CPU backend
+        if stats:
+            limit = int(stats.get("bytes_limit")
+                        or stats.get("bytes_reservable_limit") or 0)
+            limit = limit or None
         _calibrated[key] = limit
     return _calibrated[key]
 

@@ -156,3 +156,19 @@ def test_ssd_decode_matches_scan():
                                     Bm[:, t], Cm[:, t])
         np.testing.assert_allclose(np.asarray(y), np.asarray(full[:, t]),
                                    rtol=1e-4, atol=1e-4)
+
+
+
+def test_matmul_pallas_traces_inside_shard_map():
+    """The kernel's output declares the mesh axes its operands vary over,
+    which shard_map's type check requires of a pallas_call (traced only:
+    interpret mode does not run under that check)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("sites",))
+    f = jax.shard_map(matmul_pallas, mesh=mesh, in_specs=(P("sites"), P()),
+                      out_specs=P("sites"))
+    a = jax.ShapeDtypeStruct((1024, 512), jnp.float32)
+    b = jax.ShapeDtypeStruct((512, 1024), jnp.float32)
+    assert jax.eval_shape(f, a, b).shape == (1024, 1024)

@@ -377,3 +377,36 @@ def test_fault_budget_times_is_respected():
     # budget spent; same run index logic never refires
     out = eng.run(_bmm_expr(), A=A, B=B)
     assert out.data.shape == (4, 5, 2, 2)
+
+
+def _runtime_error(message: str) -> BaseException:
+    """A real ``jax.errors.JaxRuntimeError``: a host callback that raises
+    inside a jitted program fails the execution with one."""
+    def fail(x):
+        raise RuntimeError(message)
+
+    f = jax.jit(lambda x: jax.pure_callback(
+        fail, jax.ShapeDtypeStruct((2,), np.float32), x))
+    with pytest.raises(jax.errors.JaxRuntimeError) as info:
+        f(np.ones(2, np.float32)).block_until_ready()
+    return info.value
+
+
+def test_real_runtime_errors_are_classified():
+    """Regression: the classifiers once matched the class name
+    ``XlaRuntimeError``, which JAX's runtime errors no longer carry, so a
+    real device fault was neither retried nor degraded."""
+    from repro.core.engine import _is_compile_class
+    from repro.core.faults import is_transient
+    from repro.core.guards import is_oom_error
+
+    err = _runtime_error("device failure")
+    assert is_transient(err)
+    assert _is_compile_class(err)
+    assert not is_transient(RuntimeError("device failure"))
+    assert not _is_compile_class(RuntimeError("device failure"))
+
+    assert is_oom_error(_runtime_error("RESOURCE_EXHAUSTED: allocating"))
+    # the class, not the text, makes a "memory" failure an OOM
+    assert is_oom_error(_runtime_error("could not reserve device memory"))
+    assert not is_oom_error(RuntimeError("could not reserve device memory"))
