@@ -58,6 +58,7 @@ from repro.core.optimize import OptimizeResult, optimize as _optimize
 from repro.core.plan import (IAInput, IANode, Placement, TraInput, TraNode,
                              TypeInfo, as_node, describe, infer, postorder)
 from repro.core.tra import TensorRelation
+from repro.launch.metering import span
 
 EXECUTORS = ("auto", "reference", "jit", "gspmd", "shard_map")
 
@@ -279,6 +280,9 @@ class CompiledExpr:
     # per-chunk inner compiles
     streamed: bool = False
     stream_stats: Optional[object] = None   # metering.StreamStats
+    # set once run() has returned: its first call traces and compiles
+    # (or loads from JAX's persistent cache), and is recorded apart
+    ran: bool = False
 
     @property
     def plan(self):
@@ -299,6 +303,12 @@ class CompiledExpr:
         return "\n".join(describe(r) for r in self.roots)
 
     def run(self, **inputs) -> Union[TensorRelation, Tuple]:
+        with span("engine.dispatch" if self.ran else "engine.first_run"):
+            outs = self._run(inputs)
+        self.ran = True
+        return outs
+
+    def _run(self, inputs) -> Union[TensorRelation, Tuple]:
         if self.faults is not None:
             self.faults.on_run()
         # failure paths raise through repro.analysis.inputs (uniform
@@ -800,35 +810,38 @@ class Engine:
         ``_stream`` (the OOM ladder's knob) forces the fused Σ∘⋈ onto the
         chunked streaming fallback even for contraction kernel pairs.
         """
-        _validate_chunk(chunk)
-        root_names = None
-        if isinstance(expr, dict):
-            # named multi-root program (train-step state threading):
-            # run() returns {name: relation} so callers rethread
-            # state-out → state-in by name
-            root_names = tuple(expr)
-            expr = tuple(expr.values())
-        multi = isinstance(expr, (tuple, list))
-        roots = tuple(as_node(e) for e in (expr if multi else (expr,)))
-        placements = dict(self.input_placements)
-        placements.update(input_placements or {})
-        executor = self._resolve_executor()
-        chunk = self.chunk if chunk is None else chunk
+        with span("engine.lookup"):
+            _validate_chunk(chunk)
+            root_names = None
+            if isinstance(expr, dict):
+                # named multi-root program (train-step state threading):
+                # run() returns {name: relation} so callers rethread
+                # state-out → state-in by name
+                root_names = tuple(expr)
+                expr = tuple(expr.values())
+            multi = isinstance(expr, (tuple, list))
+            roots = tuple(as_node(e) for e in (expr if multi else (expr,)))
+            placements = dict(self.input_placements)
+            placements.update(input_placements or {})
+            executor = self._resolve_executor()
+            chunk = self.chunk if chunk is None else chunk
 
-        # _grad_wrt is part of the key so a value_and_grad artifact (which
-        # carries gradient semantics in .grad_wrt) never aliases a plain
-        # compile() of the structurally identical roots; the robustness
-        # fields (_stream / check_numerics / injector identity) are keyed
-        # because they are baked into the compiled callable
-        inj = self.fault_injector
-        key = (tuple(plan_sig(r) for r in roots), executor, self.optimize,
-               self.fuse, self.accounting, self.try_logical_rewrites,
-               _placements_sig(placements),
-               _placements_sig({"·": target} if target else None),
-               multi, chunk, _grad_wrt, root_names,
-               _stream, self.check_numerics,
-               None if inj is None else id(inj))
-        hit = self._cache.get(key)
+            # _grad_wrt is part of the key so a value_and_grad artifact
+            # (which carries gradient semantics in .grad_wrt) never aliases
+            # a plain compile() of the structurally identical roots; the
+            # robustness fields (_stream / check_numerics / injector
+            # identity) are keyed because they are baked into the compiled
+            # callable
+            inj = self.fault_injector
+            key = (tuple(plan_sig(r) for r in roots), executor,
+                   self.optimize, self.fuse, self.accounting,
+                   self.try_logical_rewrites,
+                   _placements_sig(placements),
+                   _placements_sig({"·": target} if target else None),
+                   multi, chunk, _grad_wrt, root_names,
+                   _stream, self.check_numerics,
+                   None if inj is None else id(inj))
+            hit = self._cache.get(key)
         if hit is not None:
             self.cache_hits += 1
             hit.hits += 1
@@ -836,8 +849,10 @@ class Engine:
         self.cache_misses += 1
         degraded_from = None
         try:
-            compiled = self._compile(roots, placements, target, executor,
-                                     multi, chunk, stream=_stream)
+            with span("engine.plan"):
+                compiled = self._compile(roots, placements, target,
+                                         executor, multi, chunk,
+                                         stream=_stream)
         except Exception as err:
             compiled, executor, err2 = self._compile_degraded(
                 err, roots, placements, target, executor, multi, chunk,

@@ -420,36 +420,42 @@ def _fused_matmul_2d(g: _JoinGeometry, left: TensorRelation,
     with ``impl="auto"`` selects the Pallas MXU kernel on TPU and the XLA
     matmul elsewhere.
     """
-    from repro.kernels.matmul.ops import matmul as matmul_op
+    from repro.kernels.matmul.ops import LAYOUT_SCOPE, matmul as matmul_op
 
     kl = g.kl
     kept_l = [ax for ax in range(kl) if ax not in jkl]
     kept_r = [ax for ax in range(kl, g.k_out)]
     m, c = left.bound
     _, n = right.bound
-    # left: (f_out_l ++ (m, c)) → (kept_l..., m, joined..., c) → 2-D
-    lperm = kept_l + [kl] + list(jkl) + [kl + 1]
-    L2 = jnp.transpose(g.ldata, lperm).reshape(
-        math.prod(g.f_out_l[ax] for ax in kept_l) * m,
-        math.prod(g.f_out_l[ax] for ax in jkl) * c)
-    # right: covered-axis order → (joined in jkl order..., c, kept_r..., n)
-    pos = {ax: i for i, ax in enumerate(g.covered)}
-    nb = len(g.covered)
-    rperm = [pos[ax] for ax in jkl] + [nb] \
-        + [pos[ax] for ax in kept_r] + [nb + 1]
-    R2 = jnp.transpose(g.rdata_t, rperm).reshape(
-        math.prod(g.f_out_l[ax] for ax in jkl) * c,
-        math.prod(g.out_key_shape[ax] for ax in kept_r) * n)
+    # the re-layouts run under the "tra.layout" scope, which names their
+    # device ops in a profile
+    with jax.named_scope(LAYOUT_SCOPE):
+        # left: (f_out_l ++ (m, c)) → (kept_l..., m, joined..., c) → 2-D
+        lperm = kept_l + [kl] + list(jkl) + [kl + 1]
+        L2 = jnp.transpose(g.ldata, lperm).reshape(
+            math.prod(g.f_out_l[ax] for ax in kept_l) * m,
+            math.prod(g.f_out_l[ax] for ax in jkl) * c)
+        # right: covered-axis order → (joined in jkl order..., c,
+        # kept_r..., n)
+        pos = {ax: i for i, ax in enumerate(g.covered)}
+        nb = len(g.covered)
+        rperm = [pos[ax] for ax in jkl] + [nb] \
+            + [pos[ax] for ax in kept_r] + [nb + 1]
+        R2 = jnp.transpose(g.rdata_t, rperm).reshape(
+            math.prod(g.f_out_l[ax] for ax in jkl) * c,
+            math.prod(g.out_key_shape[ax] for ax in kept_r) * n)
     out2 = matmul_op(L2, R2, impl=impl)
-    # back to blocks: (kept_l..., m, kept_r..., n) → gb order ++ (m, n)
-    out = out2.reshape(tuple(g.f_out_l[ax] for ax in kept_l) + (m,)
-                       + tuple(g.out_key_shape[ax] for ax in kept_r) + (n,))
-    axis_of = {ax: i for i, ax in enumerate(kept_l)}
-    for j, ax in enumerate(kept_r):
-        axis_of[ax] = len(kept_l) + 1 + j
-    perm = [axis_of[d] for d in gb] + [len(kept_l),
-                                       len(kept_l) + 1 + len(kept_r)]
-    return jnp.transpose(out, perm)
+    with jax.named_scope(LAYOUT_SCOPE):
+        # back to blocks: (kept_l..., m, kept_r..., n) → gb order ++ (m, n)
+        out = out2.reshape(
+            tuple(g.f_out_l[ax] for ax in kept_l) + (m,)
+            + tuple(g.out_key_shape[ax] for ax in kept_r) + (n,))
+        axis_of = {ax: i for i, ax in enumerate(kept_l)}
+        for j, ax in enumerate(kept_r):
+            axis_of[ax] = len(kept_l) + 1 + j
+        perm = [axis_of[d] for d in gb] + [len(kept_l),
+                                           len(kept_l) + 1 + len(kept_r)]
+        return jnp.transpose(out, perm)
 
 
 def _fused_einsum(g: _JoinGeometry, left: TensorRelation,

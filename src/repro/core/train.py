@@ -50,6 +50,7 @@ from repro.core.kernels_registry import (make_adam_dir, make_axpy,
                                          make_scale_mul)
 from repro.core.plan import TraInput, postorder
 from repro.core.tra import RelType, TensorRelation
+from repro.launch.metering import span
 
 STEP_STATE = "opt.step"                  # shared scalar step-count input
 LOSS_ROOT = "loss"                       # reserved root name
@@ -376,6 +377,10 @@ class TraTrainer:
     def step(self, **data) -> float:
         """Run one train step; returns the scalar loss (total over the
         loss relation's arrays) and advances params/state in place."""
+        with span("train.step"):
+            return self._step(data)
+
+    def _step(self, data) -> float:
         from repro.core.guards import NumericsError
         try:
             outs = self.engine.run(self.program.roots, **self.params,

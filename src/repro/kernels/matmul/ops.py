@@ -15,12 +15,17 @@ import jax.numpy as jnp
 from repro.kernels.matmul.kernel import matmul_pallas
 from repro.kernels.matmul.ref import matmul_ref
 
+# the named scope of the copies that move operands between a relation's
+# blocks and the kernel's padded 2-D layout: their device ops carry it
+LAYOUT_SCOPE = "tra.layout"
+
 
 def _pad_to(x: jax.Array, mult0: int, mult1: int) -> jax.Array:
     p0 = (-x.shape[0]) % mult0
     p1 = (-x.shape[1]) % mult1
     if p0 or p1:
-        x = jnp.pad(x, ((0, p0), (0, p1)))
+        with jax.named_scope(LAYOUT_SCOPE):
+            x = jnp.pad(x, ((0, p0), (0, p1)))
     return x
 
 
@@ -45,4 +50,5 @@ def matmul(a: jax.Array, b: jax.Array, *, impl: str = "auto",
     bp = _pad_to(b, bk, bn)
     out = matmul_pallas(ap, bp, block_m=bm, block_n=bn, block_k=bk,
                         interpret=interpret)
-    return out[:m, :n]
+    with jax.named_scope(LAYOUT_SCOPE):
+        return out[:m, :n]

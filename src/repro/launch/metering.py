@@ -25,11 +25,18 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Optional, Sequence
+import threading
+import time
+from collections import deque
+from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig, ShapeSpec
 from repro.launch.analysis import HBM_BW, ICI_BW, PEAK_FLOPS
-from repro.sharding.planner import ArchPlan, PairDecision
+
+if TYPE_CHECKING:
+    from repro.sharding.planner import ArchPlan
 
 
 @dataclasses.dataclass
@@ -139,6 +146,9 @@ def _moe_flops(cfg: ModelConfig, t: int) -> float:
 
 
 def _strategy(plan: ArchPlan, comp: str) -> str:
+    # imported here: the planner imports repro.core, which records its
+    # phase spans through this module
+    from repro.sharding.planner import PairDecision
     dec = plan.decisions.get(comp)
     if isinstance(dec, PairDecision):
         return dec.strategy
@@ -321,12 +331,10 @@ class RequestSpan:
     scheduler first packs the request into a batch (or allocates its
     decode slot), ``t_complete`` when the result is handed back.
     ``tokens`` counts produced output units (generated tokens for decode
-    servables, scored rows for stateless ones); ``artifacts`` records the
-    compile-cache ``artifact_id`` of every program dispatch that served
-    this request.  ``outcome`` is the request's fate — ``"ok"`` or one of
-    the resilience outcomes (``shed`` / ``cancelled`` / ``deadline`` /
-    ``failed``); shed spans complete without ever starting, so their
-    ``t_start`` stays ``None``.
+    servables, scored rows for stateless ones).  ``outcome`` is the
+    request's fate — ``"ok"`` or one of the resilience outcomes
+    (``shed`` / ``cancelled`` / ``deadline`` / ``failed``); shed spans
+    complete without ever starting, so their ``t_start`` stays ``None``.
     """
 
     rid: int
@@ -335,7 +343,6 @@ class RequestSpan:
     t_start: Optional[float] = None
     t_complete: Optional[float] = None
     tokens: int = 0
-    artifacts: list = dataclasses.field(default_factory=list)
     outcome: str = "ok"
 
     @property
@@ -498,3 +505,91 @@ class SpanMeter:
             "service_ms": {k: round(v * ms, 3) for k, v in percentiles(
                 [s.service_s for s in served]).items()},
         }
+
+
+# ==========================================================================
+# Phase spans: where the host's time goes inside a tick or a step
+# ==========================================================================
+
+class SpanRecorder:
+    """A bounded, always-on record of the program's phase spans.
+
+    A span is ``(name, start, end)``, its ends in seconds on
+    ``time.perf_counter``, the clock :class:`SpanMeter` stamps requests
+    with.  Spans go into a ring of ``capacity`` entries (a
+    ``deque(maxlen=capacity)`` of tuples of the name and two floats,
+    which the garbage collector stops tracking), so recording one costs
+    the same however many came before.  Once the ring is full each span
+    pushes out the oldest, which :attr:`dropped` counts: a reader can tell
+    whether the ring still holds the interval it asks about.  Per name, a
+    count and a total of seconds run over the whole process, dropped
+    spans included.
+    """
+
+    def __init__(self, capacity: int = 1 << 16) -> None:
+        if capacity < 1:
+            raise ValueError("capacity must be >= 1")
+        self.capacity = capacity
+        self.written = 0
+        self._ring: deque = deque(maxlen=capacity)
+        self._totals: Dict[str, List] = {}      # name -> [count, seconds]
+        self._lock = threading.Lock()
+
+    def span(self, name: str) -> "_Span":
+        """Context manager recording ``name`` from entry to exit.  It also
+        opens a ``jax.profiler.TraceAnnotation`` of the same name, so a
+        profile of the process shows the phase."""
+        return _Span(self, name)
+
+    def record(self, name: str, start: float, end: float) -> None:
+        """Store an interval whose ends were stamped already."""
+        with self._lock:
+            self._ring.append((name, start, end))
+            self.written += 1
+            total = self._totals.get(name)
+            if total is None:
+                self._totals[name] = [1, end - start]
+            else:
+                total[0] += 1
+                total[1] += end - start
+
+    @property
+    def dropped(self) -> int:
+        """Spans pushed out of the ring since the process started."""
+        return max(0, self.written - self.capacity)
+
+    def spans(self) -> List[Tuple[str, float, float]]:
+        """The spans the ring holds, in the order they ended."""
+        with self._lock:
+            return list(self._ring)
+
+    def totals(self) -> Dict[str, Tuple[int, float]]:
+        """Per name: (spans recorded, their total seconds)."""
+        with self._lock:
+            return {name: (n, s) for name, (n, s) in self._totals.items()}
+
+
+class _Span:
+    __slots__ = ("_recorder", "_name", "_annotation", "_start")
+
+    def __init__(self, recorder: SpanRecorder, name: str) -> None:
+        self._recorder = recorder
+        self._name = name
+
+    def __enter__(self) -> "_Span":
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        self._annotation.__exit__(*exc)
+        self._recorder.record(self._name, self._start, end)
+
+
+# The process's one recorder: the engine, the server and the trainer
+# record their phases here; the benchmark reads it after a window.
+SPANS = SpanRecorder()
+span = SPANS.span
+record = SPANS.record

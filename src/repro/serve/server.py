@@ -60,8 +60,12 @@ Resilience model (the PR-6 fault taxonomy, applied to serving):
 
 Per-request admission→completion spans are metered through
 :class:`~repro.launch.metering.SpanMeter`, splitting queue wait from
-service time, tagging each request with the artifact ids that served it
-and its outcome (ok / shed / cancelled / deadline / failed).
+service time, with each request's outcome (ok / shed / cancelled /
+deadline / failed); ``dispatches`` counts the batches each artifact
+served.  Each tick records its phases (``server.tick``, ``server.sweep``,
+``server.pack``, ``server.unpack``) and each request its queue wait
+(``server.queue``) in the process's span recorder,
+:data:`repro.launch.metering.SPANS`.
 """
 from __future__ import annotations
 
@@ -75,7 +79,7 @@ import numpy as np
 from repro.core.engine import CompiledExpr, Engine
 from repro.core.faults import is_transient
 from repro.core.tra import TensorRelation, zero_rows
-from repro.launch.metering import RequestSpan, SpanMeter
+from repro.launch.metering import RequestSpan, SpanMeter, record, span
 from repro.serve.servable import (BatchServable, LmRequest, Servable,
                                   StepServable, pick_bucket)
 
@@ -322,9 +326,10 @@ class TraServer:
 
     def step(self) -> int:
         """One scheduler tick; returns how many requests made progress."""
-        with self._step_lock:
+        with self._step_lock, span("server.tick"):
             now = self.meter.now()
-            swept = self._sweep_queue(now)
+            with span("server.sweep"):
+                swept = self._sweep_queue(now)
             if isinstance(self.servable, BatchServable):
                 progressed = self._step_batch(now)
             else:
@@ -584,13 +589,14 @@ class TraServer:
             self.counters["retry_exhausted"] += 1
         return False
 
-    def _record_dispatch(self, compiled: CompiledExpr,
-                         spans: Sequence[RequestSpan]) -> None:
+    def _record_dispatch(self, compiled: CompiledExpr) -> None:
         aid = compiled.artifact_id or "unkeyed"
         self.dispatches[aid] = self.dispatches.get(aid, 0) + 1
-        for sp in spans:
-            if not sp.artifacts or sp.artifacts[-1] != aid:
-                sp.artifacts.append(aid)
+
+    def _start(self, handle: RequestHandle) -> None:
+        """Mark the request scheduled and record its wait in the queue."""
+        self.meter.start(handle.span)
+        record("server.queue", handle.span.t_submit, handle.span.t_start)
 
     def _step_batch(self, now: float) -> int:
         sv: BatchServable = self.servable  # type: ignore[assignment]
@@ -599,7 +605,7 @@ class TraServer:
             handle = self._pop_next(now)
             if handle is None:
                 break
-            self.meter.start(handle.span)
+            self._start(handle)
             batch.append(handle)
         if not batch:
             return 0
@@ -609,10 +615,12 @@ class TraServer:
             bucket = pick_bucket(len(batch), sv.buckets)
             try:
                 compiled = self.engine.compile(sv.program(bucket))
-                self._record_dispatch(compiled, [h.span for h in batch])
-                outs = compiled.run(**sv.pack([h.payload for h in batch],
-                                              bucket), **sv.weights())
-                results = sv.unpack(outs, len(batch))
+                self._record_dispatch(compiled)
+                with span("server.pack"):
+                    packed = sv.pack([h.payload for h in batch], bucket)
+                outs = compiled.run(**packed, **sv.weights())
+                with span("server.unpack"):
+                    results = sv.unpack(outs, len(batch))
             except Exception as err:  # noqa: BLE001 — classify and retry
                 if not is_transient(err):
                     for h in batch:      # permanent: fail, keep serving
@@ -701,7 +709,7 @@ class TraServer:
             handle = self._pop_next(now)
             if handle is None:
                 break
-            self.meter.start(handle.span)
+            self._start(handle)
             self._slots[i] = _Seq(handle, handle.payload)
         live = [(i, s) for i, s in enumerate(self._slots) if s is not None]
         if not live:
@@ -714,7 +722,7 @@ class TraServer:
         # 3. ONE batched step for every slot; state threads out -> in
         try:
             compiled = self.engine.compile(sv.step_program())
-            self._record_dispatch(compiled, [s.handle.span for _, s in live])
+            self._record_dispatch(compiled)
             outs = compiled.run(**sv.step_inputs(tokens), **sv.weights(),
                                 **{"lm.state": self._state})
         except Exception as err:  # noqa: BLE001 — classify and retry
