@@ -45,7 +45,8 @@ import hashlib
 import os
 import warnings
 import weakref
-from typing import Callable, Dict, Optional, Sequence, Tuple, Union
+from typing import (Callable, Dict, Mapping, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +58,7 @@ from repro.core.interp import (_evaluate_ia, _evaluate_tra, _jit_ia_plans,
 from repro.core.optimize import OptimizeResult, optimize as _optimize
 from repro.core.plan import (IAInput, IANode, Placement, TraInput, TraNode,
                              TypeInfo, as_node, describe, infer, postorder)
-from repro.core.tra import TensorRelation
+from repro.core.tra import OperandLayout, Prelaid, TensorRelation, lay_out
 from repro.launch.metering import span
 
 EXECUTORS = ("auto", "reference", "jit", "gspmd", "shard_map")
@@ -283,6 +284,10 @@ class CompiledExpr:
     # set once run() has returned: its first call traces and compiles
     # (or loads from JAX's persistent cache), and is recorded apart
     ran: bool = False
+    # jit: the call that takes run()'s stationary inputs' contraction
+    # operands from the engine's Prelayouts, (env, names) -> outputs;
+    # None where the executor ignores the declaration
+    prelaid_call: Optional[Callable] = None
 
     @property
     def plan(self):
@@ -302,15 +307,22 @@ class CompiledExpr:
     def describe(self) -> str:
         return "\n".join(describe(r) for r in self.roots)
 
-    def run(self, **inputs) -> Union[TensorRelation, Tuple]:
+    def run(self, stationary: Optional[Mapping[str, object]] = None,
+            **inputs) -> Union[TensorRelation, Tuple]:
+        """Run on ``inputs`` by name.  ``stationary`` holds more inputs
+        that the caller passes as the same buffers from call to call, such
+        as a server's weights: on ``jit`` their contraction operands are
+        laid out once and kept by the engine (:class:`Prelayouts`); the
+        other executors take them as ordinary inputs."""
         with span("engine.dispatch" if self.ran else "engine.first_run"):
-            outs = self._run(inputs)
+            outs = self._run(inputs, stationary or {})
         self.ran = True
         return outs
 
-    def _run(self, inputs) -> Union[TensorRelation, Tuple]:
+    def _run(self, inputs, stationary) -> Union[TensorRelation, Tuple]:
         if self.faults is not None:
             self.faults.on_run()
+        inputs = {**inputs, **stationary}
         # failure paths raise through repro.analysis.inputs (uniform
         # diagnostics, legacy exception types/text); imports stay off the
         # happy path
@@ -335,7 +347,10 @@ class CompiledExpr:
             if holey:
                 from repro.analysis.inputs import masked_inputs_error
                 raise masked_inputs_error(self.executor, holey)
-        outs = self._call(env)
+        if stationary and self.prelaid_call is not None:
+            outs = self.prelaid_call(env, frozenset(stationary))
+        else:
+            outs = self._call(env)
         if self.root_names is not None:
             return dict(zip(self.root_names, outs))
         return outs if self.multi else outs[0]
@@ -375,6 +390,50 @@ class CacheEntry:
     # (repro.launch.metering.StreamStats) for artifacts compiled through
     # the host relation store; None for resident artifacts
     stream_stats: Optional[object] = None
+
+
+class Prelayouts:
+    """Laid-out contraction operands of the inputs callers declare
+    stationary (``CompiledExpr.run(stationary=...)``): one copy per source
+    buffer and :class:`~repro.core.tra.OperandLayout`, shared by every
+    artifact of the engine.
+
+    Each copy is made by the first dispatch that needs it, under the span
+    ``engine.prelayout``.  An input name holds the buffer last passed under
+    it alive; passing another buffer under the name drops the old one's
+    copies, unless another name still passes it.
+    """
+
+    def __init__(self) -> None:
+        self._sources: Dict[str, object] = {}
+        self._copies: Dict[Tuple[int, OperandLayout], jax.Array] = {}
+        self.hits = 0
+        self.misses = 0
+
+    def __len__(self) -> int:
+        return len(self._copies)
+
+    @property
+    def nbytes(self) -> int:
+        return sum(c.nbytes for c in self._copies.values())
+
+    def operand(self, name: str, source, layout: OperandLayout):
+        old = self._sources.get(name)
+        if old is not source:
+            self._sources[name] = source
+            if old is not None and all(s is not old
+                                       for s in self._sources.values()):
+                self._copies = {k: c for k, c in self._copies.items()
+                                if k[0] != id(old)}
+        key = (id(source), layout)
+        copy = self._copies.get(key)
+        if copy is not None:
+            self.hits += 1
+            return copy
+        self.misses += 1
+        with span("engine.prelayout"):
+            copy = self._copies[key] = lay_out(layout, source)
+        return copy
 
 
 def _coerce(name: str, value, rtype, keep_host: bool = False):
@@ -562,6 +621,7 @@ class Engine:
         self._cache: Dict[Tuple, _CacheSlot] = {}
         self.cache_hits = 0
         self.cache_misses = 0
+        self.prelayouts = Prelayouts()
 
     # -- host relation store (out-of-core tier) ---------------------------
     @property
@@ -1111,7 +1171,9 @@ class Engine:
         # program instead.  Flag labels are recorded at trace time
         # (re-recorded on retrace), one list per variant.
 
-        def make_fn(ectx):
+        def make_fn(ectx, laid=()):
+            # laid: (stationary input, its layouts) pairs whose operands
+            # the program takes, laid out, after its inputs
             labels: list = []
 
             def fn(*arrays):
@@ -1119,6 +1181,10 @@ class Engine:
                     ectx.flags.clear()   # stale flags from aborted traces
                 env = {n: TensorRelation(a, rtypes[n])
                        for n, a in zip(names, arrays)}
+                ops = iter(arrays[len(names):])
+                for n, layouts in laid:
+                    env[n] = Prelaid(env[n].data, rtypes[n], operands={
+                        lay: next(ops) for lay in layouts})
                 outs = eval_all(env, ectx)
                 datas = tuple(r.data for r in outs)
                 if ectx is not None and ectx.check:
@@ -1165,15 +1231,13 @@ class Engine:
                             f"{lab} (jit finite-flags; plan postorder "
                             f"attribution)", node_label=lab)
 
-        def call(env):
-            args = tuple(env[n].data for n in names)
-            res = jfn(*args)
+        def finish(res, args, labels):
             datas, flags = res[:nout], res[nout:]
             if flags and not bool(flags[0]):
                 from repro.core.guards import NumericsError
                 if check != "all":
                     attribute(args)   # raises when it reproduces
-                for lab, fl in zip(flag_labels, flags[1:]):
+                for lab, fl in zip(labels, flags[1:]):
                     if not bool(fl):
                         raise NumericsError(
                             f"non-finite values first produced by node "
@@ -1185,8 +1249,47 @@ class Engine:
             return tuple(TensorRelation(d, oi.rtype, oi.mask)
                          for d, oi in zip(datas, out_infos))
 
+        def call(env):
+            args = tuple(env[n].data for n in names)
+            return finish(jfn(*args), args, flag_labels)
+
+        def layouts_of(stationary, args):
+            """(input, layouts) of the stationary inputs' contraction
+            operands that the program would lay out itself, found by an
+            abstract trace."""
+            seen: Dict[str, Prelaid] = {}
+
+            def probe(*arrays):
+                env = {n: (Prelaid if n in stationary else TensorRelation)(
+                    a, rtypes[n]) for n, a in zip(names, arrays)}
+                seen.update((n, env[n]) for n in stationary)
+                return tuple(r.data for r in eval_all(env, None))
+
+            jax.eval_shape(probe, *args)
+            return tuple((n, tuple(seen[n].missing)) for n in sorted(seen)
+                         if seen[n].missing)
+
+        variants: dict = {}   # stationary names -> (laid, program, labels)
+
+        def prelaid_call(env, stationary):
+            args = tuple(env[n].data for n in names)
+            if stationary not in variants:
+                laid = layouts_of(stationary, args)
+                fn2, labels2 = make_fn(None, laid)
+                variants[stationary] = (laid, jax.jit(fn2), labels2)
+            laid, jfn2, labels2 = variants[stationary]
+            if not laid:
+                return call(env)
+            ops = tuple(self.prelayouts.operand(n, env[n].data, lay)
+                        for n, layouts in laid for lay in layouts)
+            return finish(jfn2(*args, *ops), args, labels2)
+
+        # a robustness context (faults, per-node checks, streaming) runs
+        # every dispatch through the one program it was built into
         return CompiledExpr("jit", plans, rtypes, out_infos, call, opts,
-                            multi, jitted=jfn, input_names=tuple(names))
+                            multi, jitted=jfn, input_names=tuple(names),
+                            prelaid_call=prelaid_call if ctx is None
+                            else None)
 
     def _gspmd_call(self, plans, out_infos, chunk, ctx=None):
         jfn, names, shardings = _jit_ia_plans(

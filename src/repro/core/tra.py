@@ -22,8 +22,9 @@ Two executors share this module:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -209,6 +210,8 @@ class _JoinGeometry:
     rdata_t: jax.Array
     lmask: Optional[np.ndarray]
     rmask_t: Optional[np.ndarray]
+    r_keep: Tuple[int, ...]           # right's frontier, in its own axes
+    r_order: Tuple[int, ...]          # right's key axis of each of rdata_t's
 
 
 def _join_align(left: TensorRelation, right: TensorRelation,
@@ -262,7 +265,8 @@ def _join_align(left: TensorRelation, right: TensorRelation,
             r_shape.append(1)
     return _JoinGeometry(kl, kr, k_out, tuple(f_out_l), out_key_shape,
                          covered, tuple(r_shape), ldata, rdata_t,
-                         lmask, rmask_t)
+                         lmask, rmask_t, tuple(rdata.shape[:kr]),
+                         tuple(order))
 
 
 def join(left: TensorRelation, right: TensorRelation,
@@ -408,6 +412,89 @@ def _zero_fill(data: jax.Array, mask: Optional[np.ndarray],
     return jnp.where(m, data, jnp.zeros((), data.dtype))
 
 
+@dataclasses.dataclass(frozen=True)
+class OperandLayout:
+    """How the fused 2-D contraction lays out an input relation's payload
+    as an operand of the Pallas kernel: keep the frontier ``keep`` of the
+    key axes, transpose by ``perm``, reshape to ``shape2d`` and zero-pad to
+    multiples of ``tile``, the kernel's blocks.  It names the operand:
+    equal layouts of one buffer are one operand."""
+
+    src_shape: Tuple[int, ...]
+    dtype: str
+    keep: Tuple[int, ...]
+    perm: Tuple[int, ...]
+    shape2d: Tuple[int, int]
+    tile: Tuple[int, int]
+
+    @property
+    def copies(self) -> bool:
+        """False where the operand is the source's own bytes reshaped: no
+        slice, no pad, and no axis longer than one moves."""
+        shape = self.keep + self.src_shape[len(self.keep):]
+        if shape != self.src_shape or any(
+                s % t for s, t in zip(self.shape2d, self.tile)):
+            return True
+        moved = [a for a in self.perm if shape[a] > 1]
+        return moved != sorted(moved)
+
+    def apply(self, data: jax.Array) -> jax.Array:
+        from repro.kernels.matmul.ops import LAYOUT_SCOPE, pad_operand
+        with jax.named_scope(LAYOUT_SCOPE):
+            x = _to_2d(data[tuple(slice(0, k) for k in self.keep)],
+                       self.perm, self.shape2d)
+        return pad_operand(x, self.tile)
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def lay_out(layout: OperandLayout, data: jax.Array) -> jax.Array:
+    """``data`` as the operand ``layout`` describes, in one program."""
+    return layout.apply(data)
+
+
+@dataclasses.dataclass
+class Prelaid(TensorRelation):
+    """An input relation whose contraction operands were laid out before
+    the program ran (the caller declared it stationary): ``operands`` by
+    :class:`OperandLayout`.  A layout the contraction asks for and does
+    not find is noted in ``missing`` and laid out in the program."""
+
+    operands: Dict[OperandLayout, jax.Array] = dataclasses.field(
+        default_factory=dict)
+    missing: List[OperandLayout] = dataclasses.field(default_factory=list)
+
+    def operand(self, layout: OperandLayout) -> Optional[jax.Array]:
+        if not layout.copies:
+            return None
+        op = self.operands.get(layout)
+        if op is None and layout not in self.missing:
+            self.missing.append(layout)
+        return op
+
+
+def _to_2d(data: jax.Array, perm, shape2d) -> jax.Array:
+    return jnp.transpose(data, perm).reshape(shape2d)
+
+
+def _operand_2d(rel: TensorRelation, data: jax.Array, keep, src_perm,
+                perm, shape2d, impl: str) -> jax.Array:
+    """One operand of the 2-D contraction: ``data`` (``rel``'s payload cut
+    to ``keep`` and transposed by ``src_perm``) transposed by ``perm`` to
+    ``shape2d``, or the kernel's laid-out copy a :class:`Prelaid` input
+    carries.  XLA's dot takes the operand from the program, where XLA may
+    fold its layout into the product."""
+    from repro.kernels.matmul.ops import LAYOUT_SCOPE, tile_multiples
+    tile = tile_multiples(shape2d, impl)
+    if isinstance(rel, Prelaid) and tile is not None:
+        op = rel.operand(OperandLayout(
+            tuple(rel.data.shape), str(rel.data.dtype), tuple(keep),
+            tuple(src_perm[a] for a in perm), shape2d, tile))
+        if op is not None:
+            return op
+    with jax.named_scope(LAYOUT_SCOPE):
+        return _to_2d(data, perm, shape2d)
+
+
 def _fused_matmul_2d(g: _JoinGeometry, left: TensorRelation,
                      right: TensorRelation, jkl: Tuple[int, ...],
                      gb: Tuple[int, ...], impl: str = "auto") -> jax.Array:
@@ -418,7 +505,8 @@ def _fused_matmul_2d(g: _JoinGeometry, left: TensorRelation,
     the paper's claim that the TRA plan *is* the hand-tuned contraction.
     Dispatches through :func:`repro.kernels.matmul.ops.matmul`, which
     with ``impl="auto"`` selects the Pallas MXU kernel on TPU and the XLA
-    matmul elsewhere.
+    matmul elsewhere.  A :class:`Prelaid` operand arrives already in the
+    2-D layout, padded as the kernel pads it.
     """
     from repro.kernels.matmul.ops import LAYOUT_SCOPE, matmul as matmul_op
 
@@ -427,24 +515,24 @@ def _fused_matmul_2d(g: _JoinGeometry, left: TensorRelation,
     kept_r = [ax for ax in range(kl, g.k_out)]
     m, c = left.bound
     _, n = right.bound
+    rows = math.prod(g.f_out_l[ax] for ax in kept_l) * m
+    inner = math.prod(g.f_out_l[ax] for ax in jkl) * c
+    cols = math.prod(g.out_key_shape[ax] for ax in kept_r) * n
     # the re-layouts run under the "tra.layout" scope, which names their
     # device ops in a profile
-    with jax.named_scope(LAYOUT_SCOPE):
-        # left: (f_out_l ++ (m, c)) → (kept_l..., m, joined..., c) → 2-D
-        lperm = kept_l + [kl] + list(jkl) + [kl + 1]
-        L2 = jnp.transpose(g.ldata, lperm).reshape(
-            math.prod(g.f_out_l[ax] for ax in kept_l) * m,
-            math.prod(g.f_out_l[ax] for ax in jkl) * c)
-        # right: covered-axis order → (joined in jkl order..., c,
-        # kept_r..., n)
-        pos = {ax: i for i, ax in enumerate(g.covered)}
-        nb = len(g.covered)
-        rperm = [pos[ax] for ax in jkl] + [nb] \
-            + [pos[ax] for ax in kept_r] + [nb + 1]
-        R2 = jnp.transpose(g.rdata_t, rperm).reshape(
-            math.prod(g.f_out_l[ax] for ax in jkl) * c,
-            math.prod(g.out_key_shape[ax] for ax in kept_r) * n)
-    out2 = matmul_op(L2, R2, impl=impl)
+    # left: (f_out_l ++ (m, c)) → (kept_l..., m, joined..., c) → 2-D
+    lperm = kept_l + [kl] + list(jkl) + [kl + 1]
+    L2 = _operand_2d(left, g.ldata, g.f_out_l, tuple(range(kl + 2)),
+                     lperm, (rows, inner), impl)
+    # right: covered-axis order → (joined in jkl order..., c,
+    # kept_r..., n)
+    pos = {ax: i for i, ax in enumerate(g.covered)}
+    nb = len(g.covered)
+    rperm = [pos[ax] for ax in jkl] + [nb] \
+        + [pos[ax] for ax in kept_r] + [nb + 1]
+    R2 = _operand_2d(right, g.rdata_t, g.r_keep, g.r_order + (nb, nb + 1),
+                     rperm, (inner, cols), impl)
+    out2 = matmul_op(L2, R2, impl=impl, out_shape=(rows, cols))
     with jax.named_scope(LAYOUT_SCOPE):
         # back to blocks: (kept_l..., m, kept_r..., n) → gb order ++ (m, n)
         out = out2.reshape(

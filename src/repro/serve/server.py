@@ -618,7 +618,7 @@ class TraServer:
                 self._record_dispatch(compiled)
                 with span("server.pack"):
                     packed = sv.pack([h.payload for h in batch], bucket)
-                outs = compiled.run(**packed, **sv.weights())
+                outs = compiled.run(**packed, stationary=sv.weights())
                 with span("server.unpack"):
                     results = sv.unpack(outs, len(batch))
             except Exception as err:  # noqa: BLE001 — classify and retry
@@ -723,8 +723,9 @@ class TraServer:
         try:
             compiled = self.engine.compile(sv.step_program())
             self._record_dispatch(compiled)
-            outs = compiled.run(**sv.step_inputs(tokens), **sv.weights(),
-                                **{"lm.state": self._state})
+            outs = compiled.run(**sv.step_inputs(tokens),
+                                **{"lm.state": self._state},
+                                stationary=sv.weights())
         except Exception as err:  # noqa: BLE001 — classify and retry
             self._on_decode_failure(live, err)
             return reclaimed + len(live)
