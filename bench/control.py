@@ -11,7 +11,8 @@ For each of ``--control-seeds`` it puts the reference, computed in a
 lower precision (``bfloat16``, ``int8`` and, to show the program's own
 precision, ``bfloat16_operands``), in the program's place and prints the
 same numbers against the float32 reference; a train cell also gets the
-reference with half of each batch left out.  The smallest of these is a
+reference with half of each batch left out and, on a mesh, the one whose
+sites leave out the exchange of their gradients.  The smallest of these is a
 number's upper reading.  Everything runs in this one process, on the
 chips the cell asks for.
 """
@@ -50,6 +51,9 @@ def control_numbers(cell, seed: int, n_requests: int):
         for p in CONTROLS:
             out[p] = model.train_numbers(ref(precision=p), want)
         out["half_batch"] = model.train_numbers(ref(fault="half_batch"), want)
+        if "mesh" in cfg:
+            out["no_exchange"] = model.train_numbers(ref(fault="no_exchange"),
+                                                     want)
     else:
         payloads = model.make_requests(cfg, seed, n_requests)
         want = model.reference_scores(cfg, seed, payloads)

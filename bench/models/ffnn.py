@@ -118,10 +118,11 @@ def minibatch_count(cfg, minibatches: int) -> int:
     return min(minibatches, cfg.get("feature_sets", minibatches))
 
 
-def make_train_data(cfg, seed: int, minibatches: int):
-    """W1, W2 and ``minibatches`` (X, Y) pairs, made on the default device
-    in one jitted call.  Minibatch k has labels of its own and the
-    features of set ``k % feature_sets``."""
+def make_train_data(cfg, seed: int, minibatches: int, shardings=None):
+    """W1, W2 and ``minibatches`` (X, Y) pairs, made in one jitted call:
+    on the default device, or laid out as ``shardings`` where given:
+    (W1's, W2's, every X's, every Y's).  Minibatch k has
+    labels of its own and the features of set ``k % feature_sets``."""
     fsets = minibatch_count(cfg, minibatches)
 
     def make(lo, hi):
@@ -131,7 +132,11 @@ def make_train_data(cfg, seed: int, minibatches: int):
         ys = [_labels(cfg, base, k, xs[k % fsets]) for k in range(minibatches)]
         return w1, w2, xs, ys
 
-    return jax.jit(make)(*seed_words(seed))
+    if shardings is None:
+        return jax.jit(make)(*seed_words(seed))
+    w1, w2, x, y = shardings
+    out = (w1, w2, [x] * fsets, [y] * minibatches)
+    return jax.jit(make, out_shardings=out)(*seed_words(seed))
 
 
 def make_weights(cfg, seed: int):
@@ -214,6 +219,54 @@ def _train_step_fn(cfg, precision: str):
     return jax.jit(step)
 
 
+def _blocked_step_fn(cfg, precision: str, rows: int, sites: int = 0):
+    """One SGD step of the plain model over the batch in blocks of
+    ``rows`` rows, the gradient summed over the blocks, so that no
+    activation of the whole batch is alive at once.  Returns
+    (loss, W1', W2').
+
+    With ``sites``, the batch falls into ``sites`` equal blocks instead,
+    and each site's stored share of W1 and of W2 (a contiguous
+    ``1/sites`` of their rows, as TRA-DP stores them) takes the gradient
+    of that site's block alone: the exchange of the gradients between
+    the sites left out."""
+    cast, mm = precision_ops(precision)
+    lr = cfg["lr"]
+
+    @jax.jit
+    def grads(x, y, w1, w2):
+        x = cast(x)
+        a1 = jax.nn.relu(mm(x, w1))
+        a2 = jax.nn.sigmoid(mm(a1, w2))
+        d2 = (a2.astype(jnp.float32) - y).astype(a2.dtype)
+        g2 = mm(a1.T, d2).astype(w2.dtype)
+        da1 = mm(d2, w2.T) * (a1 > 0)
+        g1 = mm(x.T, da1).astype(w1.dtype)
+        return _bce(a2, y), g1, g2
+
+    @jax.jit
+    def update(w, g):
+        return (w - lr * g).astype(w.dtype)
+
+    def own_rows(g, site):
+        """``g`` with the rows outside ``site``'s share zeroed."""
+        owner = jnp.arange(g.shape[0]) * sites // g.shape[0]
+        return jnp.where((owner == site)[:, None], g, 0)
+
+    def step(x, y, w1, w2):
+        n = x.shape[0]
+        size = n // sites if sites else rows
+        loss, g1, g2 = 0.0, 0, 0
+        for i, lo in enumerate(range(0, n, size)):
+            l, a, b = grads(x[lo:lo + size], y[lo:lo + size], w1, w2)
+            if sites:
+                a, b = own_rows(a, i), own_rows(b, i)
+            loss, g1, g2 = loss + l, g1 + a, g2 + b
+        return loss, update(w1, g1), update(w2, g2)
+
+    return step
+
+
 def _norm(a):
     return jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32))))
 
@@ -227,9 +280,15 @@ def reference_train(cfg, seed: int, minibatches: int, steps: int, *,
     SGD got it, ``‖W0 − W1‖/lr`` (worked out from the state, as the
     program's is), and each leaf's norm of change after the last step,
     as host floats.
-    ``fault="half_batch"`` plants a fault in the reference put in the
-    program's place: the second half of every batch is replaced by the
-    first, so the sum runs over half of the batch, doubled."""
+    ``fault`` plants a fault in the reference put in the program's
+    place.  ``half_batch``: the second half of every batch is replaced by
+    the first, so the sum runs over half of the batch, doubled.
+    ``no_exchange``: each of the configuration's ``mesh`` sites updates
+    its stored share of the weights with the gradient of its own share
+    of the batch alone.
+
+    A configuration with ``reference_rows_per_block`` runs the step over
+    the batch in blocks of that many rows."""
     w1, w2, xs, ys = make_train_data(cfg, seed, minibatches)
     cast, _ = precision_ops(precision)
     # one array at a time, so that a layout and its dense copy are the
@@ -238,7 +297,14 @@ def reference_train(cfg, seed: int, minibatches: int, steps: int, *,
     w2 = cast(dense(w2))
     xs = [dense(xs.pop(0)) for _ in range(len(xs))]
     ys = [dense(y) for y in ys]
-    step = _train_step_fn(cfg, precision)
+    rows = cfg.get("reference_rows_per_block")
+    if fault == "no_exchange":
+        sites = math.prod(cfg["mesh"]["shape"])
+        step = _blocked_step_fn(cfg, precision, rows, sites)
+    elif rows:
+        step = _blocked_step_fn(cfg, precision, rows)
+    else:
+        step = _train_step_fn(cfg, precision)
     losses, grad_norms = [], None
     for k in range(steps):
         x, y = xs[k % len(xs)], ys[k % minibatches]
@@ -246,7 +312,7 @@ def reference_train(cfg, seed: int, minibatches: int, steps: int, *,
             h = x.shape[0] // 2
             x = jnp.concatenate([x[:h], x[:h]])
             y = jnp.concatenate([y[:h], y[:h]])
-        elif fault:
+        elif fault not in ("", "no_exchange"):
             raise ValueError(f"unknown fault {fault!r}")
         loss, w1, w2 = step(x, y, w1, w2)
         losses.append(float(loss))

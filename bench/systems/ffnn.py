@@ -8,7 +8,9 @@ relations.
 """
 from __future__ import annotations
 
+import collections
 import gc
+import math
 from typing import Dict, List
 
 import models.ffnn as model
@@ -17,6 +19,35 @@ import models.ffnn as model
 def _engine():
     from repro.core import Engine
     return Engine(executor="jit")
+
+
+def mesh_engine(cfg, devices):
+    """The engine over the configuration's ``mesh`` of the first of
+    ``devices``, with its executor and the input placements that
+    ``repro.core.programs.<placements>`` gives; and the sharding of each
+    placed input by name, over the relation layout ``(k0, k1, b0, b1)``
+    (an input placed nowhere is replicated)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import Engine, programs
+    spec = cfg["mesh"]
+    shape = tuple(spec["shape"])
+    grid = np.array(devices[:math.prod(shape)]).reshape(shape)
+    mesh = Mesh(grid, tuple(spec["axes"]))
+    placements = getattr(programs, spec["placements"])(
+        *model.block_dims(cfg)[:4])
+
+    def sharding(p):
+        if p.is_replicated:
+            return NamedSharding(mesh, P())
+        return NamedSharding(mesh, P(*(p.axis_of_dim(d) for d in range(2))))
+
+    engine = Engine(mesh, executor=spec["executor"],
+                    input_placements=placements)
+    shardings = collections.defaultdict(lambda: NamedSharding(mesh, P()))
+    shardings.update({n: sharding(p) for n, p in placements.items()})
+    return engine, shardings
 
 
 def _rel(array, keys, block):
@@ -31,7 +62,11 @@ class TrainSession:
     traffic's ``minibatches``, in turn) and returns the loss on the host.
     ``first_steps(n)`` runs the first ``n`` and returns what the check
     compares: each step's loss, each leaf's first gradient as SGD got it,
-    ``(W0 − W1)/lr``, and each leaf's change after step ``n``."""
+    ``(W0 − W1)/lr``, and each leaf's change after step ``n``.
+
+    A configuration with a ``mesh`` runs on :func:`mesh_engine` over the
+    first chips; the data and weights are made there, laid out as
+    placed."""
 
     def __init__(self, cfg, traffic, seed: int):
         from repro.core import SGD, TraTrainer
@@ -39,14 +74,21 @@ class TrainSession:
         self.cfg, self.seed = cfg, seed
         nb, db, hb, lb, bn, bd, bh, bl = dims = model.block_dims(cfg)
         self.minibatches = traffic["minibatches"]
-        w1, w2, xs, ys = model.make_train_data(cfg, seed, self.minibatches)
+        if "mesh" in cfg:
+            import jax
+            engine, shardings = mesh_engine(cfg, jax.devices())
+            shardings = tuple(shardings[n] for n in ("W1", "W2", "X", "Y"))
+        else:
+            engine, shardings = _engine(), None
+        w1, w2, xs, ys = model.make_train_data(cfg, seed, self.minibatches,
+                                               shardings)
         xs = [_rel(x, (nb, db), (bn, bd)) for x in xs]
         self.feed: List[Dict[str, object]] = [
             {"X": xs[k % len(xs)], "Y": _rel(y, (nb, lb), (bn, bl))}
             for k, y in enumerate(ys)]
         step = ffnn_train_step_tra(*dims, optimizer=SGD(cfg["lr"]))
         self.trainer = TraTrainer(
-            _engine(), step,
+            engine, step,
             params={"W1": _rel(w1, (db, hb), (bd, bh)),
                     "W2": _rel(w2, (hb, lb), (bh, bl))})
 

@@ -28,32 +28,15 @@ def test_sound_run_is_correct(traffic):
 
 def test_state_left_unchanged_is_caught(monkeypatch):
     from repro.core import TraTrainer
-    step = TraTrainer.step
-
-    def frozen(self, **data):
-        params, state = self.params, self.state
-        loss = step(self, **data)
-        self.params, self.state = params, state
-        return loss
-
-    monkeypatch.setattr(TraTrainer, "step", frozen)
+    monkeypatch.setattr(TraTrainer, "step", tiny.frozen(TraTrainer.step))
     run = _run(tiny.cell("speech-100k", "train"))
     assert not run.correct
     assert run.checks["change_gap"][0] == pytest.approx(1.0)
 
 
 def test_half_of_the_batch_left_out_is_caught(monkeypatch):
-    from repro.core import TensorRelation, TraTrainer
-    step = TraTrainer.step
-
-    def half(self, **data):
-        def first_half(rel):
-            d = rel.data
-            h = d.shape[0] // 2
-            return TensorRelation(d.at[h:].set(d[:h]), rel.rtype)
-        return step(self, **{k: first_half(v) for k, v in data.items()})
-
-    monkeypatch.setattr(TraTrainer, "step", half)
+    from repro.core import TraTrainer
+    monkeypatch.setattr(TraTrainer, "step", tiny.half_batch(TraTrainer.step))
     run = _run(tiny.cell("speech-100k", "train"))
     assert not run.correct
 

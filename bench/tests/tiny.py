@@ -40,3 +40,28 @@ def cell(config_name: str, traffic_name: str, **traffic_over):
 
 def devices():
     return jax.devices()[:1]
+
+
+def frozen(step):
+    """``TraTrainer.step`` that returns its state unchanged."""
+    def patched(self, **data):
+        params, state = self.params, self.state
+        loss = step(self, **data)
+        self.params, self.state = params, state
+        return loss
+    return patched
+
+
+def half_batch(step):
+    """``TraTrainer.step`` that sees the first half of its batch twice:
+    the sum taken over half of the batch, doubled."""
+    from repro.core import TensorRelation
+
+    def first_half(rel):
+        d = rel.data
+        h = d.shape[0] // 2
+        return TensorRelation(d.at[h:].set(d[:h]), rel.rtype)
+
+    def patched(self, **data):
+        return step(self, **{k: first_half(v) for k, v in data.items()})
+    return patched
