@@ -889,11 +889,27 @@ def pack_rows(rows: Sequence, bucket: int, rtype: RelType
     Programs that never contract the batch key dim compute each row
     independently, so the padding rows are inert (see
     ``tests/test_serve.py`` for the masked-tail oracle).
+
+    Host rows (anything that is neither a relation nor a device array)
+    are written into one zero-padded host buffer that crosses to the
+    device in a single transfer; rows already on the device are stacked
+    there.
     """
     if not 0 < len(rows) <= bucket:
         raise ValueError(
             f"pack_rows: {len(rows)} rows do not fit bucket {bucket}")
     dense = tuple(rtype.key_shape) + tuple(rtype.bound)
+    if not any(isinstance(r, (TensorRelation, jax.Array)) for r in rows):
+        buf = np.zeros((bucket,) + dense, rtype.dtype)
+        for i, r in enumerate(rows):
+            arr = np.asarray(r, rtype.dtype)
+            if arr.shape != dense:
+                raise ValueError(
+                    f"pack_rows: row {i} has dense shape {arr.shape}, "
+                    f"expected {dense}")
+            buf[i] = arr
+        return TensorRelation(jax.device_put(buf),
+                              _batched_rtype(rtype, bucket))
     datas = []
     for i, r in enumerate(rows):
         if isinstance(r, TensorRelation):

@@ -196,10 +196,12 @@ class FFNNScorer(BatchServable):
     # -- request packing ---------------------------------------------------
     def pack(self, payloads: Sequence, bucket: int
              ) -> Dict[str, TensorRelation]:
+        """One host-to-device transfer for the batch: the payloads are
+        checked and laid out on the host (``tra.pack_rows``)."""
         from repro.core.tra import pack_rows
         rows = []
         for p in payloads:
-            arr = jnp.asarray(p, jnp.float32)
+            arr = np.asarray(p, np.float32)
             if arr.shape != (self.d_in,):
                 raise ValueError(
                     f"scorer request must be a ({self.d_in},) feature "
@@ -208,9 +210,10 @@ class FFNNScorer(BatchServable):
         return {"X": pack_rows(rows, bucket, self._row_rtype)}
 
     def unpack(self, outs: Dict[str, TensorRelation], n: int) -> List:
-        from repro.core.tra import unpack_rows
-        return [np.asarray(r.data).reshape(self.d_out)
-                for r in unpack_rows(outs["scores"], n)]
+        """One device-to-host copy of the batch's scores, sliced into
+        the first ``n`` rows on the host."""
+        scores = jax.device_get(outs["scores"].data)
+        return [scores[i].reshape(self.d_out) for i in range(n)]
 
     def random_payload(self, rng: np.random.Generator) -> np.ndarray:
         return rng.standard_normal(self.d_in).astype(np.float32)
